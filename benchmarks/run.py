@@ -138,6 +138,9 @@ def main() -> int:
     smoke = "--smoke" in sys.argv
     if smoke:
         os.environ["BENCH_SMOKE"] = "1"
+    from repro.serving import jitcache
+
+    jitcache.enable_compile_cache()
     failures = 0
     module_status = {}
     for name, desc in MODULES:

@@ -2,9 +2,10 @@
 
 Two conveniences over the raw kernels in ``kernel.py``:
 
-* **auto-interpret** — off-TPU backends run the Pallas interpreter
+* **auto-interpret** — the CPU backend runs the Pallas interpreter
   (pure-JAX semantics, bit-exact math), so the same call sites work on
-  CPU tests and TPU serving;
+  CPU tests and TPU serving; any other backend raises
+  (:func:`repro.kernels.default_interpret`);
 * **mesh sharding** — pass ``mesh=`` (an instance's slice, axes
   ("data", "model")) and the kernel runs under ``shard_map`` with the
   **head dimension partitioned over the model axis**: attention
@@ -25,6 +26,7 @@ from typing import Optional
 import jax
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import default_interpret
 from repro.kernels.decode_attention.kernel import decode_attention as _kernel
 from repro.kernels.decode_attention.kernel import (
     paged_decode_attention as _paged_kernel,
@@ -60,15 +62,13 @@ def decode_attention(
     axis: str = "model",
 ) -> jax.Array:
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     call = functools.partial(
         _kernel, window=window, softcap=softcap, block_c=block_c,
         interpret=interpret,
     )
     if _heads_shardable(mesh, axis, q.shape[1], k_cache.shape[2]):
-        from jax.experimental.shard_map import shard_map
-
-        call = shard_map(
+        call = jax.shard_map(
             call, mesh=mesh,
             in_specs=(
                 P(None, axis, None),        # q (B, Hq, Dh)
@@ -78,7 +78,7 @@ def decode_attention(
                 P(None),                    # q_pos (B,) replicated
             ),
             out_specs=P(None, axis, None),
-            check_rep=False,
+            check_vma=False,
         )
     return call(q, k_cache, v_cache, slot_pos, q_pos)
 
@@ -97,14 +97,12 @@ def paged_decode_attention(
     axis: str = "model",
 ) -> jax.Array:
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     call = functools.partial(
         _paged_kernel, window=window, softcap=softcap, interpret=interpret,
     )
     if _heads_shardable(mesh, axis, q.shape[1], k_pages.shape[2]):
-        from jax.experimental.shard_map import shard_map
-
-        call = shard_map(
+        call = jax.shard_map(
             call, mesh=mesh,
             in_specs=(
                 P(None, axis, None),        # q (B, Hq, Dh)
@@ -114,7 +112,7 @@ def paged_decode_attention(
                 P(None),                    # lengths (B,) replicated
             ),
             out_specs=P(None, axis, None),
-            check_rep=False,
+            check_vma=False,
         )
     return call(q, k_pages, v_pages, block_tables, lengths)
 
@@ -133,14 +131,12 @@ def paged_verify_attention(
     axis: str = "model",
 ) -> jax.Array:
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     call = functools.partial(
         _verify_kernel, window=window, softcap=softcap, interpret=interpret,
     )
     if _heads_shardable(mesh, axis, q.shape[2], k_pages.shape[2]):
-        from jax.experimental.shard_map import shard_map
-
-        call = shard_map(
+        call = jax.shard_map(
             call, mesh=mesh,
             in_specs=(
                 P(None, None, axis, None),  # q (B, T, Hq, Dh)
@@ -150,6 +146,6 @@ def paged_verify_attention(
                 P(None),                    # lengths (B,) replicated
             ),
             out_specs=P(None, None, axis, None),
-            check_rep=False,
+            check_vma=False,
         )
     return call(q, k_pages, v_pages, block_tables, lengths)

@@ -1,7 +1,7 @@
 """Jit'd public wrapper for the flash-attention kernel.
 
-Selects interpret mode automatically off-TPU so tests validate the kernel
-body on CPU; on TPU the same call lowers to Mosaic.
+Interprets the kernel on the CPU backend so tests validate its body
+there; on TPU the same call lowers to Mosaic; any other backend raises.
 """
 from __future__ import annotations
 
@@ -9,6 +9,7 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention as _kernel
 
 
@@ -25,7 +26,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return _kernel(
         q, k, v,
         causal=causal, window=window, softcap=softcap,

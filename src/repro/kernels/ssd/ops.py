@@ -1,10 +1,12 @@
-"""Jit'd public wrapper for the SSD scan kernel (auto-interpret on CPU)."""
+"""Jit'd public wrapper for the SSD scan kernel (interpreted on CPU,
+Mosaic on TPU, an error elsewhere)."""
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
 
+from repro.kernels import default_interpret
 from repro.kernels.ssd.kernel import ssd_scan as _kernel
 
 
@@ -19,5 +21,5 @@ def ssd_scan(
     interpret: Optional[bool] = None,
 ):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return _kernel(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)

@@ -8,16 +8,11 @@ init, and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # AxisType landed after jax 0.4.x; older jax defaults to Auto anyway
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # pragma: no cover - depends on installed jax
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {}
+def _axis_kwargs(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,7 +23,7 @@ import json
 
 from repro.configs.registry import REGISTRY
 from repro.core.power import CHIPS
-from repro.serving import ClusterConfig, PDCluster, poisson_workload
+from repro.serving import ClusterConfig, PDCluster, jitcache, poisson_workload
 from repro.serving.traces import load_trace, rescale_to_rps
 from repro.serving.workload import DATASETS, azure_like, synthetic_pd_ratio
 
@@ -87,6 +87,7 @@ def main():
 
     if args.scenario is not None and args.trace is not None:
         ap.error("--scenario and --trace are mutually exclusive")
+    jitcache.enable_compile_cache()
 
     chip = CHIPS[args.chip]
     model = REGISTRY[args.arch]

@@ -31,14 +31,23 @@ It also centralizes the two serving-wide jit policies:
   run to report ``RunMetrics.recompiles``, and the perf-invariant tests
   pin the steady-state value at zero.
 
+It also owns the persistent compilation cache
+(:func:`enable_compile_cache`), which entry points turn on and tests
+leave off.
+
 ``jax`` is imported lazily: a pure-:class:`SimBackend` process that only
 ever *reads* the counter (every ``PDCluster.run``) never pays the jax
 import.
 """
 from __future__ import annotations
 
+import os
 from functools import partial
+from pathlib import Path
 from typing import Callable, Dict, Optional, Tuple
+
+# the checkout holding this package (src/repro/serving/jitcache.py)
+_CHECKOUT = Path(__file__).resolve().parents[3]
 
 _CACHE: Dict[tuple, Callable] = {}  # key -> raw jax.jit object
 # key -> the callable handed out (the jit itself, or its mesh-entering
@@ -99,6 +108,26 @@ def shared_jit(fn: Callable, cfg, *, donate: Tuple[str, ...] = (),
         handed._shared_jit = j  # telemetry/tests reach the raw jit
     _HANDED[key] = handed
     return handed
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives in the checkout's
+    ``.jax_cache/`` (gitignored).  The directory must stay put between
+    runs for entries to be found again, so it is never derived from a
+    temporary name, a process id or the time.  Called by the program's
+    entry points (``chip_smoke.py``, ``repro.launch.serve``,
+    ``benchmarks/run.py``), never by the tests."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def compile_count() -> int:

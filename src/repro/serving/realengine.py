@@ -103,6 +103,16 @@ def _bucket(n: int, lo: int = 16, hi: Optional[int] = None) -> int:
     return b
 
 
+def _snapshot(a: np.ndarray) -> jax.Array:
+    """Device copy of host state the backend keeps mutating (slot
+    positions, block tables).  ``jnp.asarray`` of a NumPy array may
+    alias its memory or copy it after returning, while the step it
+    feeds runs asynchronously; the host then advances ``pos`` and grows
+    or clears table rows for the next iteration, and the in-flight step
+    would read those.  A private copy that nothing mutates is safe."""
+    return jnp.asarray(a.copy())
+
+
 class RealBackend(SimBackend):
     """Executes real JAX forwards; inherits Sim timing/energy accounting."""
 
@@ -578,32 +588,20 @@ class RealBackend(SimBackend):
         if self.paged:
             # grow tail pages where the next write crosses a boundary
             for r in reqs:
-                s = self.slot_of[r.rid]
-                table = self.table_of[r.rid]
-                need = int(self.pos[s]) + 1
-                try:
-                    fresh = table.ensure(need)
-                except PageAllocError:
-                    short = self.pool.pages_for(need) - len(table.pages)
-                    if not self._evict_radix_for(short):
-                        raise
-                    fresh = table.ensure(need)
-                if fresh:
-                    n = len(table.pages)
-                    self.block_tables[s, n - len(fresh): n] = fresh
+                self.reserve_pages(r, int(self.pos[self.slot_of[r.rid]]) + 1)
             ids, self.kvcache = self._decode_jit(
                 self.params,
                 tokens=self._next_dev,
                 cache=self.kvcache,
-                lengths=jnp.asarray(self.pos),
-                block_tables=jnp.asarray(self.block_tables),
+                lengths=_snapshot(self.pos),
+                block_tables=_snapshot(self.block_tables),
             )
         else:
             ids, self.cache = self._decode_jit(
                 self.params,
                 tokens=self._next_dev,
                 cache=self.cache,
-                lengths=jnp.asarray(self.pos),
+                lengths=_snapshot(self.pos),
             )
         # chain on device; emission of `ids` waits for the next drain
         self._next_dev = ids
@@ -638,8 +636,14 @@ class RealBackend(SimBackend):
         position is below them).
         """
         s = self.slot_of[r.rid]
+        self.reserve_pages(r, min(int(self.pos[s]) + k + 1, self.max_len))
+
+    def reserve_pages(self, r: Request, need: int) -> None:
+        """Grow ``r``'s block table to cover ``need`` tokens (shedding
+        cold radix pages if the pool is short) and mirror the fresh
+        tail pages into its slot's row of the device block table."""
+        s = self.slot_of[r.rid]
         table = self.table_of[r.rid]
-        need = min(int(self.pos[s]) + k + 1, self.max_len)
         try:
             fresh = table.ensure(need)
         except PageAllocError:
@@ -700,8 +704,8 @@ class RealBackend(SimBackend):
             self.params,
             tokens=toks,
             cache=self.kvcache,
-            lengths=jnp.asarray(self.pos),
-            block_tables=jnp.asarray(self.block_tables),
+            lengths=_snapshot(self.pos),
+            block_tables=_snapshot(self.block_tables),
         )
         match = M.accept_prefix(drafts, tgt)
         # chain update on device with the host-known acceptance

@@ -155,3 +155,18 @@ def test_real_backend_slot_reuse(rc, rparams):
     be.release(reqs[0])
     be.insert(reqs[2])  # reuses the freed slot
     assert be.slot_of[reqs[2].rid] in (0, 1)
+
+
+def test_snapshot_is_not_an_alias_of_host_state():
+    """Slot positions and block tables are host arrays the backend keeps
+    mutating while the step they fed is still in flight.  A plain
+    ``jnp.asarray`` of a NumPy array can alias it on the CPU backend
+    (the in-flight step then reads the next iteration's tables); the
+    snapshot must not."""
+    from repro.serving.realengine import _snapshot
+
+    for _ in range(20):
+        host = np.zeros((64, 1024), np.int32)
+        dev = _snapshot(host)
+        host[:] = -1
+        assert int(jnp.abs(dev).max()) == 0

@@ -753,6 +753,8 @@ class PDCluster:
 
     def _route_prefill(self, req: Request) -> None:
         req.cached_len = req.computed_len = 0  # (re-)entering prefill
+        if self._prof is not None:
+            self._prof.queued(req.rid)
         if self.autoscaler is not None:
             self.autoscaler.maybe_wake_prefill(self.now, req.prompt_len)
         views = [

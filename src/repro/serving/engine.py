@@ -145,6 +145,8 @@ def _binding_itl_s(running: List[Request]) -> Optional[float]:
 class SimBackend:
     """Latency/energy from the hardware model with measurement noise."""
 
+    _prof = None  # loopprof.LoopProfile, set by loopprof.install
+
     def __init__(self, hw: HardwareModel, noise_sigma: float = 0.02,
                  seed: int = 0, slow_factor: float = 1.0,
                  batch_pricing: bool = False):
@@ -455,6 +457,9 @@ class PrefillEngine(ParkableEngine):
         else:
             batch, _ = self.form_batch()
             takes = [r.prefill_remaining for r in batch]
+        prof = self.backend._prof
+        if prof is not None:
+            prof.taken(batch)
         n_new = sum(takes)
         n_ctx = sum(r.cached_len + r.computed_len for r in batch)
         self.current_batch = batch
@@ -952,6 +957,9 @@ class HybridEngine(DecodeEngine):
         if not self.running and not batch:
             self.busy = False
             return None
+        prof = self.backend._prof
+        if prof is not None:
+            prof.taken(batch)
         self.p_current, self._p_takes = batch, takes
         n_new = sum(takes)
         n_ctx = sum(r.cached_len + r.computed_len for r in batch)

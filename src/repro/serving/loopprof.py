@@ -46,12 +46,61 @@ instrumented too: the installer registers itself on the cluster's
 breakdown's ``accounted_frac`` stays honest.  Wrapping costs a couple of
 ``perf_counter`` calls per iteration, so install it for breakdown runs,
 not for the headline iterations/s row.
+
+**Spans.**  ``install`` also sets the profile on every backend (and on
+the cluster), and the served path appends :class:`Span` records to
+``profile.spans`` where the work happens:
+
+* ``queue.prefill`` — from the cluster handing a request to the prefill
+  router (an arrival or a re-route) to the prefill step that takes it;
+* ``step.prefill`` — ``RealBackend._real_prefill``; child
+  ``wait.first_token``, the host blocked on the first token;
+* ``step.insert`` — the P→D handoff into a decode slot, whole;
+* ``step.decode`` — the dispatch of one decode (or draft–verify) step;
+* ``wait.drain`` — the host blocked on an in-flight step's token ids,
+  a child of whatever forced it (an insert, the next decode dispatch;
+  top level under a release or the end-of-run flush).
+
+Each span holds host start and end (``perf_counter``), the serving
+clock (``cluster.now``) at both ends, its parent's index and the request
+id or the step's rids.  Step spans carry ``compiled``: whether
+:func:`repro.serving.jitcache.compile_count` rose inside them
+(``profile.compiles`` sums the rise).  While a profile is attached each
+span is also a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``,
+so inside a profiler trace the spans sit on the device trace's clock.
+With no profile attached a site costs nothing but ``is None`` tests.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from time import perf_counter
-from typing import Dict, List
+from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from repro.serving import jitcache
+
+
+class Span:
+    """One timed piece of served work (see the module docstring)."""
+
+    __slots__ = ("name", "t0", "t1", "now0", "now1", "parent", "rid",
+                 "attrs", "_ann", "_c0")
+
+    def __init__(self, name: str, parent: int, rid: Optional[int],
+                 attrs: dict):
+        self.name, self.parent, self.rid, self.attrs = (
+            name, parent, rid, attrs)
+        self.t1: Optional[float] = None  # None while open
+        self.now1: Optional[float] = None
+
+    @property
+    def dur(self) -> float:
+        return self.t1 - self.t0
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, t0={self.t0}, t1={self.t1}, "
+                f"parent={self.parent}, rid={self.rid}, {self.attrs})")
 
 
 @dataclass
@@ -64,9 +113,93 @@ class LoopProfile:
     queue_s: float = 0.0        # event-heap pops (profiled drain)
     bookkeeping_s: float = 0.0  # handler wall minus the probes above
     iterations: int = 0
+    spans: List[Span] = field(default_factory=list)
+    compiles: int = 0  # jit compiles inside step spans
     _engines: List = field(default_factory=list)   # live, grows on spawn
     _backends: List = field(default_factory=list)  # live, grows on spawn
     _routers: List = field(default_factory=list)
+    _cluster: object = None  # serving clock: ``_cluster.now``
+    _stack: List[int] = field(default_factory=list)  # open nested spans
+    _queued: Dict[int, Span] = field(default_factory=dict)  # rid -> open
+    _inst: Dict[int, str] = field(default_factory=dict)  # id(backend)
+    _steps: Dict[int, Span] = field(default_factory=dict)  # id(entry)
+
+    # -- spans -----------------------------------------------------------
+    def _start(self, sp: Span) -> Span:
+        sp._ann = TraceAnnotation("repro." + sp.name)
+        sp._ann.__enter__()
+        sp.t0 = perf_counter()
+        sp.now0 = self._cluster.now
+        return sp
+
+    def _stop(self, sp: Span) -> None:
+        sp.t1 = perf_counter()
+        sp._ann.__exit__(None, None, None)
+        sp._ann = None
+        sp.now1 = self._cluster.now
+
+    def begin(self, name: str, rid: Optional[int] = None,
+              **attrs) -> Span:
+        """Open a span nested in the innermost open one."""
+        sp = Span(name, self._stack[-1] if self._stack else -1, rid, attrs)
+        self._stack.append(len(self.spans))
+        self.spans.append(sp)
+        return self._start(sp)
+
+    def end(self, sp: Span, **attrs) -> Span:
+        self._stop(sp)
+        sp.attrs.update(attrs)
+        while self._stack:  # a span an exception left open goes too
+            if self.spans[self._stack.pop()] is sp:
+                break
+        return sp
+
+    def begin_step(self, name: str, rid: Optional[int] = None,
+                   **attrs) -> Span:
+        """A step span: it also notes whether a jit compiled inside."""
+        c0 = jitcache.compile_count()
+        sp = self.begin(name, rid, **attrs)
+        sp._c0 = c0
+        return sp
+
+    def end_step(self, sp: Span, **attrs) -> Span:
+        self.end(sp, **attrs)
+        n = jitcache.compile_count() - sp._c0
+        sp.attrs["compiled"] = n > 0
+        self.compiles += n
+        return sp
+
+    def begin_decode(self, backend, reqs) -> Span:
+        return self.begin_step(
+            "step.decode", inst=self._inst.get(id(backend)),
+            seq=backend.pipeline_dispatches,
+            rids=tuple(r.rid for r in reqs))
+
+    def end_decode(self, sp: Span, entry) -> None:
+        """Close a ``step.decode``; ``entry`` is the deferred emission
+        its ``wait.drain`` will pop."""
+        self.end_step(sp)
+        self._steps[id(entry)] = sp
+
+    def begin_drain(self, entry) -> Span:
+        step = self._steps.pop(id(entry), None)
+        return self.begin(
+            "wait.drain", seq=None if step is None else step.attrs["seq"],
+            rids=tuple(x[0].rid for x in entry[1]))
+
+    def queued(self, rid: int) -> None:
+        """A request goes to the prefill router: its ``queue.prefill``
+        opens, at top level (it closes in a later event)."""
+        sp = Span("queue.prefill", -1, rid, {})
+        self.spans.append(sp)
+        self._queued[rid] = self._start(sp)
+
+    def taken(self, reqs) -> None:
+        """A prefill step takes these requests: their waits close."""
+        for r in reqs:
+            sp = self._queued.pop(r.rid, None)
+            if sp is not None:
+                self._stop(sp)
 
     def _device_wait(self) -> float:
         return sum(
@@ -141,7 +274,7 @@ def install(cluster) -> LoopProfile:
     the wrappers accumulate into.  Registers on the cluster's
     ``_spawn_hooks`` so engines spawned mid-run (scale-out) are wrapped
     identically."""
-    prof = LoopProfile()
+    prof = LoopProfile(_cluster=cluster)
 
     def timed(fn, attr, count=False):
         def wrapper(*a, **k):
@@ -167,6 +300,8 @@ def install(cluster) -> LoopProfile:
                               count=True))
         prof._engines.append(eng)
         prof._backends.append(eng.backend)
+        prof._inst[id(eng.backend)] = eng.energy.name
+        eng.backend._prof = prof  # the spans
 
     for eng in (list(cluster.prefill) + list(cluster.decode)
                 + list(cluster.hybrid)):

@@ -265,12 +265,6 @@ class RealBackend(SimBackend):
             self._verify_jit = jitcache.shared_jit(
                 M.verify_step_paged_greedy, cfg, donate=don, **jit_kw
             )
-            # token-match telemetry: what greedy accept-prefix sampling
-            # would have accepted (the control plane's acceptance
-            # *realization* is the engine's — backend-independent, so
-            # Sim==Real parity holds through the speculative path)
-            self.spec_real_matches = 0
-            self.spec_real_drafted = 0
 
     # ------------------------------------------------------------------
     # Paged plumbing
@@ -359,28 +353,31 @@ class RealBackend(SimBackend):
         **only** place the host blocks on device results — called when
         the ring reaches ``pipeline_depth`` at dispatch, at a slot
         insert/release, or the end-of-run flush.  Oldest-first order
-        keeps each request's stream append-ordered."""
+        keeps each request's stream append-ordered.  With a profile
+        attached the wait is a ``wait.drain`` span, and
+        ``device_wait_s`` adds the span's length."""
         p = self._ring.popleft()
+        prof = self._prof
+        sp = None if prof is None else prof.begin_drain(p)
         t0 = time.perf_counter()
         if p[0] == "decode":
             _, pairs, ids = p
             nxt = np.asarray(ids)
-            self.device_wait_s += time.perf_counter() - t0
+            self.device_wait_s += (time.perf_counter() - t0 if sp is None
+                                   else prof.end(sp).dur)
             for r, s in pairs:
                 r.output_tokens.append(int(nxt[s]))
         else:  # spec: accepted draft prefix + bonus/correction token
-            _, entries, drafts_dev, tgt_dev, match_dev = p
+            _, entries, drafts_dev, tgt_dev = p
             drafts = np.asarray(drafts_dev)
             tgt = np.asarray(tgt_dev)
-            match = np.asarray(match_dev)
-            self.device_wait_s += time.perf_counter() - t0
+            self.device_wait_s += (time.perf_counter() - t0 if sp is None
+                                   else prof.end(sp).dur)
             for r, s, a in entries:
                 r.output_tokens.extend(
                     int(drafts[s, j]) for j in range(a)
                 )
                 r.output_tokens.append(int(tgt[s, a]))
-                self.spec_real_matches += int(match[s])
-                self.spec_real_drafted += self.spec_k
 
     def _drain(self) -> None:
         """Drain the whole ring: every deferred iteration is emitted, in
@@ -423,10 +420,27 @@ class RealBackend(SimBackend):
                 f"exceeds the decode cache capacity ({self.max_len}); "
                 "admission must reject or truncate it upstream"
             )
+        prof = self._prof
+        if prof is not None:
+            sp = prof.begin_step("step.prefill", r.rid)
+            reused0 = self.reused_tokens if self.paged else 0
         if self.paged:
             self._real_prefill_paged(r, toks)
         else:
             self._real_prefill_dense(r, toks)
+        if prof is not None:
+            reused = self.reused_tokens - reused0 if self.paged else 0
+            prof.end_step(sp, computed=len(toks) - reused, reused=reused)
+
+    def _first_token(self, ids) -> int:
+        """The prefill's first token: the host blocks on it here."""
+        prof = self._prof
+        if prof is None:
+            return int(ids[0])
+        sp = prof.begin("wait.first_token")
+        tok = int(ids[0])
+        prof.end(sp)
+        return tok
 
     def _real_prefill_dense(self, r: Request, toks: np.ndarray) -> None:
         ids, cache = self._prefill_jit(
@@ -435,7 +449,7 @@ class RealBackend(SimBackend):
             lengths=jnp.asarray([len(toks)], jnp.int32),
         )
         if not r.resuming:
-            r.output_tokens.append(int(ids[0]))
+            r.output_tokens.append(self._first_token(ids))
         r.kv_handoff = cache  # migrates with the request (P -> D)
 
     def _real_prefill_paged(self, r: Request, toks: np.ndarray) -> None:
@@ -467,7 +481,7 @@ class RealBackend(SimBackend):
             cache=self.kvcache,
         )
         if not r.resuming:
-            r.output_tokens.append(int(ids[0]))
+            r.output_tokens.append(self._first_token(ids))
         # migration payload: the request's pages, gathered page-stack —
         # the decode side scatters them into its own pool
         idx = np.asarray(table)
@@ -505,6 +519,9 @@ class RealBackend(SimBackend):
     # ------------------------------------------------------------------
     def insert(self, req: Request) -> None:
         assert self.free, "no free decode slots (max_running too high?)"
+        prof = self._prof
+        if prof is not None:
+            sp = prof.begin("step.insert", req.rid)
         self._drain()  # the joining token seeds the device chain below
         slot = self.free.pop()
         self.slot_of[req.rid] = slot
@@ -550,6 +567,9 @@ class RealBackend(SimBackend):
         self.pos[slot] = req.prompt_len + req.tokens_out
         if self.spec_k > 0:
             self._draft_prefill(req, slot)
+        if prof is not None:
+            prof.end(sp, pages=len(self.table_of[req.rid].pages)
+                     if self.paged else 0)
 
     def _draft_prefill(self, req: Request, slot: int) -> None:
         """Build the drafter's dense cache for a joining request: the
@@ -581,6 +601,9 @@ class RealBackend(SimBackend):
             self.block_tables[slot] = -1
 
     def _real_decode_step(self, reqs: List[Request]) -> None:
+        prof = self._prof
+        if prof is not None:
+            sp = prof.begin_decode(self, reqs)
         # bounded depth: block only once pipeline_depth iterations are
         # in flight, and then only on the oldest (depth=1: the previous)
         while len(self._ring) >= self.pipeline_depth:
@@ -613,6 +636,8 @@ class RealBackend(SimBackend):
         self._ring.append(("decode", pairs, ids))
         self.pipeline_depth_sum += len(self._ring)
         self.pipeline_dispatches += 1
+        if prof is not None:
+            prof.end_decode(sp, self._ring[-1])
 
     def decode_iter(self, reqs: List[Request], n_req: int, n_kv: int,
                     f: float):
@@ -669,6 +694,9 @@ class RealBackend(SimBackend):
         bonus/correction token, and the pages holding only rejected
         positions are returned to the pool (page-exact rollback).
         """
+        prof = self._prof
+        if prof is not None:
+            sp = prof.begin_decode(self, reqs)
         while len(self._ring) >= self.pipeline_depth:
             self._drain_one()
         for r in reqs:
@@ -707,7 +735,6 @@ class RealBackend(SimBackend):
             lengths=_snapshot(self.pos),
             block_tables=_snapshot(self.block_tables),
         )
-        match = M.accept_prefix(drafts, tgt)
         # chain update on device with the host-known acceptance
         # realization: prev <- last accepted draft (or the old pending
         # token when a == 0), next <- the verify pass's bonus/correction
@@ -729,7 +756,7 @@ class RealBackend(SimBackend):
         )
         self._prev_dev = jnp.where(occ, new_prev, self._prev_dev)
         self._next_dev = jnp.where(occ, tgt[rows, a_dev], self._next_dev)
-        self._ring.append(("spec", entries, drafts, tgt, match))
+        self._ring.append(("spec", entries, drafts, tgt))
         self.pipeline_depth_sum += len(self._ring)
         self.pipeline_dispatches += 1
         for r, s, a in entries:
@@ -738,6 +765,8 @@ class RealBackend(SimBackend):
             table = self.table_of[r.rid]
             table.shrink(int(self.pos[s]))
             self.block_tables[s, len(table.pages):] = -1
+        if prof is not None:
+            prof.end_decode(sp, self._ring[-1])
 
     def spec_decode_iter(self, reqs: List[Request], n_req: int, n_kv: int,
                          k: int, accepts: List[int], draft_frac: float,

@@ -1,0 +1,5 @@
+"""The chip benchmark's ``chipbench/tests/test_spans.py``, collected with
+the repo's tests."""
+from _chipbench import load
+
+globals().update(load("test_spans"))

@@ -317,6 +317,8 @@ class ParkableEngine:
     instead of idle draw until it is re-admitted.
     """
 
+    _prof = None  # loopprof.LoopProfile, set by loopprof.install
+
     def drain(self) -> None:
         self.accepting = False
 
@@ -457,7 +459,7 @@ class PrefillEngine(ParkableEngine):
         else:
             batch, _ = self.form_batch()
             takes = [r.prefill_remaining for r in batch]
-        prof = self.backend._prof
+        prof = self._prof
         if prof is not None:
             prof.taken(batch)
         n_new = sum(takes)
@@ -957,7 +959,7 @@ class HybridEngine(DecodeEngine):
         if not self.running and not batch:
             self.busy = False
             return None
-        prof = self.backend._prof
+        prof = self._prof
         if prof is not None:
             prof.taken(batch)
         self.p_current, self._p_takes = batch, takes

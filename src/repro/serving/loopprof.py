@@ -18,8 +18,9 @@ for the per-phase wall split the benchmark harness publishes in
 * ``dispatch`` — backend iteration calls' host time (Sim: hwmodel
   pricing; Real: jit dispatch — *not* device completion, which the async
   backend defers).
-* ``device_wait`` — host time truly blocked on device transfers (real
-  backends' deferred-emission drains; 0 in pure simulation).
+* ``device_wait`` — host time truly blocked on device transfers: the
+  sum of the real backends' ``wait.drain`` spans (below); 0 in pure
+  simulation.
 * ``metrics``  — ``finish_iteration`` bookkeeping + straggler-bias
   re-prediction at ``_D_DONE``.
 * ``queue``    — event-heap pops (the loop's only unavoidable
@@ -63,11 +64,14 @@ the cluster), and the served path appends :class:`Span` records to
 
 Each span holds host start and end (``perf_counter``), the serving
 clock (``cluster.now``) at both ends, its parent's index and the request
-id or the step's rids.  Step spans carry ``compiled``: whether
-:func:`repro.serving.jitcache.compile_count` rose inside them
-(``profile.compiles`` sums the rise).  While a profile is attached each
-span is also a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``,
-so inside a profiler trace the spans sit on the device trace's clock.
+id or the step's rids.  Step spans (prefill, insert, decode) carry
+``compiled``: whether an XLA backend compile ran inside them, counted
+from JAX's ``/jax/core/compile/backend_compile_duration`` monitoring
+event, which jitted steps and eager ops (the insert's page scatter)
+both emit, persistent-cache loads included.  ``profile.compiles`` sums
+them.  While a profile is attached each span is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so inside a
+profiler trace the spans sit on the device trace's clock.
 With no profile attached a site costs nothing but ``is None`` tests.
 """
 from __future__ import annotations
@@ -76,9 +80,19 @@ from dataclasses import dataclass, field
 from time import perf_counter
 from typing import Dict, List, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
-from repro.serving import jitcache
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_compiles = [0]  # XLA backend compiles since this module was imported
+
+
+def _count_compile(event: str, duration: float, **_) -> None:
+    if event == _BACKEND_COMPILE:
+        _compiles[0] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 class Span:
@@ -114,7 +128,7 @@ class LoopProfile:
     bookkeeping_s: float = 0.0  # handler wall minus the probes above
     iterations: int = 0
     spans: List[Span] = field(default_factory=list)
-    compiles: int = 0  # jit compiles inside step spans
+    compiles: int = 0  # XLA backend compiles inside step spans
     _engines: List = field(default_factory=list)   # live, grows on spawn
     _backends: List = field(default_factory=list)  # live, grows on spawn
     _routers: List = field(default_factory=list)
@@ -122,7 +136,6 @@ class LoopProfile:
     _stack: List[int] = field(default_factory=list)  # open nested spans
     _queued: Dict[int, Span] = field(default_factory=dict)  # rid -> open
     _inst: Dict[int, str] = field(default_factory=dict)  # id(backend)
-    _steps: Dict[int, Span] = field(default_factory=dict)  # id(entry)
 
     # -- spans -----------------------------------------------------------
     def _start(self, sp: Span) -> Span:
@@ -156,36 +169,22 @@ class LoopProfile:
 
     def begin_step(self, name: str, rid: Optional[int] = None,
                    **attrs) -> Span:
-        """A step span: it also notes whether a jit compiled inside."""
-        c0 = jitcache.compile_count()
+        """A step span: it also notes whether XLA compiled inside."""
+        c0 = _compiles[0]
         sp = self.begin(name, rid, **attrs)
         sp._c0 = c0
         return sp
 
     def end_step(self, sp: Span, **attrs) -> Span:
         self.end(sp, **attrs)
-        n = jitcache.compile_count() - sp._c0
+        n = _compiles[0] - sp._c0
         sp.attrs["compiled"] = n > 0
         self.compiles += n
         return sp
 
-    def begin_decode(self, backend, reqs) -> Span:
-        return self.begin_step(
-            "step.decode", inst=self._inst.get(id(backend)),
-            seq=backend.pipeline_dispatches,
-            rids=tuple(r.rid for r in reqs))
-
-    def end_decode(self, sp: Span, entry) -> None:
-        """Close a ``step.decode``; ``entry`` is the deferred emission
-        its ``wait.drain`` will pop."""
-        self.end_step(sp)
-        self._steps[id(entry)] = sp
-
-    def begin_drain(self, entry) -> Span:
-        step = self._steps.pop(id(entry), None)
-        return self.begin(
-            "wait.drain", seq=None if step is None else step.attrs["seq"],
-            rids=tuple(x[0].rid for x in entry[1]))
+    def instance(self, backend) -> Optional[str]:
+        """The name of the engine that ``backend`` serves."""
+        return self._inst.get(id(backend))
 
     def queued(self, rid: int) -> None:
         """A request goes to the prefill router: its ``queue.prefill``
@@ -202,9 +201,8 @@ class LoopProfile:
                 self._stop(sp)
 
     def _device_wait(self) -> float:
-        return sum(
-            getattr(b, "device_wait_s", 0.0) for b in self._backends
-        )
+        return sum(s.t1 - s.t0 for s in self.spans
+                   if s.name == "wait.drain" and s.t1 is not None)
 
     def _select_memo_rate(self) -> float:
         hits = misses = 0
@@ -301,7 +299,7 @@ def install(cluster) -> LoopProfile:
         prof._engines.append(eng)
         prof._backends.append(eng.backend)
         prof._inst[id(eng.backend)] = eng.energy.name
-        eng.backend._prof = prof  # the spans
+        eng._prof = eng.backend._prof = prof  # the spans
 
     for eng in (list(cluster.prefill) + list(cluster.decode)
                 + list(cluster.hybrid)):

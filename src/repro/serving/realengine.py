@@ -71,7 +71,6 @@ the KV heads.  A ``tp=1`` mesh is bit-exact with the meshless path.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
@@ -175,7 +174,6 @@ class RealBackend(SimBackend):
             )
         self.pipeline_depth = pipeline_depth
         self._ring: Deque[Tuple] = deque()
-        self.device_wait_s = 0.0  # host time spent blocked on transfers
         # occupancy telemetry: mean ring depth observed at dispatch
         self.pipeline_depth_sum = 0
         self.pipeline_dispatches = 0
@@ -354,25 +352,27 @@ class RealBackend(SimBackend):
         the ring reaches ``pipeline_depth`` at dispatch, at a slot
         insert/release, or the end-of-run flush.  Oldest-first order
         keeps each request's stream append-ordered.  With a profile
-        attached the wait is a ``wait.drain`` span, and
-        ``device_wait_s`` adds the span's length."""
+        attached the wait is a ``wait.drain`` span."""
         p = self._ring.popleft()
         prof = self._prof
-        sp = None if prof is None else prof.begin_drain(p)
-        t0 = time.perf_counter()
+        if prof is not None:
+            sp = prof.begin(
+                "wait.drain",
+                seq=self.pipeline_dispatches - len(self._ring) - 1,
+                rids=tuple(x[0].rid for x in p[1]))
         if p[0] == "decode":
             _, pairs, ids = p
             nxt = np.asarray(ids)
-            self.device_wait_s += (time.perf_counter() - t0 if sp is None
-                                   else prof.end(sp).dur)
+            if prof is not None:
+                prof.end(sp)
             for r, s in pairs:
                 r.output_tokens.append(int(nxt[s]))
         else:  # spec: accepted draft prefix + bonus/correction token
             _, entries, drafts_dev, tgt_dev = p
             drafts = np.asarray(drafts_dev)
             tgt = np.asarray(tgt_dev)
-            self.device_wait_s += (time.perf_counter() - t0 if sp is None
-                                   else prof.end(sp).dur)
+            if prof is not None:
+                prof.end(sp)
             for r, s, a in entries:
                 r.output_tokens.extend(
                     int(drafts[s, j]) for j in range(a)
@@ -521,7 +521,7 @@ class RealBackend(SimBackend):
         assert self.free, "no free decode slots (max_running too high?)"
         prof = self._prof
         if prof is not None:
-            sp = prof.begin("step.insert", req.rid)
+            sp = prof.begin_step("step.insert", req.rid)
         self._drain()  # the joining token seeds the device chain below
         slot = self.free.pop()
         self.slot_of[req.rid] = slot
@@ -568,8 +568,8 @@ class RealBackend(SimBackend):
         if self.spec_k > 0:
             self._draft_prefill(req, slot)
         if prof is not None:
-            prof.end(sp, pages=len(self.table_of[req.rid].pages)
-                     if self.paged else 0)
+            prof.end_step(sp, pages=len(self.table_of[req.rid].pages)
+                          if self.paged else 0)
 
     def _draft_prefill(self, req: Request, slot: int) -> None:
         """Build the drafter's dense cache for a joining request: the
@@ -603,7 +603,10 @@ class RealBackend(SimBackend):
     def _real_decode_step(self, reqs: List[Request]) -> None:
         prof = self._prof
         if prof is not None:
-            sp = prof.begin_decode(self, reqs)
+            sp = prof.begin_step(
+                "step.decode", inst=prof.instance(self),
+                seq=self.pipeline_dispatches,
+                rids=tuple(r.rid for r in reqs))
         # bounded depth: block only once pipeline_depth iterations are
         # in flight, and then only on the oldest (depth=1: the previous)
         while len(self._ring) >= self.pipeline_depth:
@@ -637,7 +640,7 @@ class RealBackend(SimBackend):
         self.pipeline_depth_sum += len(self._ring)
         self.pipeline_dispatches += 1
         if prof is not None:
-            prof.end_decode(sp, self._ring[-1])
+            prof.end_step(sp)
 
     def decode_iter(self, reqs: List[Request], n_req: int, n_kv: int,
                     f: float):
@@ -696,7 +699,10 @@ class RealBackend(SimBackend):
         """
         prof = self._prof
         if prof is not None:
-            sp = prof.begin_decode(self, reqs)
+            sp = prof.begin_step(
+                "step.decode", inst=prof.instance(self),
+                seq=self.pipeline_dispatches,
+                rids=tuple(r.rid for r in reqs))
         while len(self._ring) >= self.pipeline_depth:
             self._drain_one()
         for r in reqs:
@@ -766,7 +772,7 @@ class RealBackend(SimBackend):
             table.shrink(int(self.pos[s]))
             self.block_tables[s, len(table.pages):] = -1
         if prof is not None:
-            prof.end_decode(sp, self._ring[-1])
+            prof.end_step(sp)
 
     def spec_decode_iter(self, reqs: List[Request], n_req: int, n_kv: int,
                          k: int, accepts: List[int], draft_frac: float,

@@ -697,14 +697,18 @@ def init_paged_cache(
     return cache
 
 
-def _gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
+def _gather_pages(
+    pages: jax.Array, block_tables: jax.Array, layer=None
+) -> jax.Array:
     """(P+1, ps, H, D) pages × (B, Pmax) tables -> (B, Pmax*ps, H, D)
     dense per-sequence view (−1 table entries clamp to page 0; callers
-    mask by length)."""
+    mask by length).  With ``layer``, ``pages`` is the whole stacked
+    ``(n_blocks, P+1, ps, H, D)`` pool and only that layer's pages are
+    read: one gather, no slice of the layer."""
     B, Pmax = block_tables.shape
-    ps = pages.shape[1]
-    g = pages[jnp.maximum(block_tables, 0)]  # (B, Pmax, ps, H, D)
-    return g.reshape(B, Pmax * ps, *pages.shape[2:])
+    idx = jnp.maximum(block_tables, 0)
+    g = pages[idx] if layer is None else pages[layer, idx]
+    return g.reshape(B, Pmax * g.shape[2], *g.shape[3:])
 
 
 def prefill_paged(
@@ -846,6 +850,13 @@ def verify_step_paged(
     the next accepted tokens overwrite them.
 
     With ``T == 1`` this is exactly :func:`decode_step_paged`.
+
+    The pool travels in the layer scan's **carry**, and each layer's
+    pages are written and read in it by layer index, so under donation
+    the step updates the pool in place.  It must not ride in ``xs`` /
+    ``ys``: the scan would then read one pool and write another, and XLA
+    keeps them apart by copying the whole donated pool into the loop and
+    slicing each layer out and back every step.
     """
     _check_paged(cfg)
     x = jnp.take(params["embed"], tokens, axis=0)  # (B, T, d)
@@ -866,15 +877,14 @@ def verify_step_paged(
     slot_pos = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (B, C))
 
     def body(carry, xs):
-        xc = carry  # (B, T, d)
-        bp, cache_in = xs
+        xc, pool = carry  # (B, T, d), the whole stacked pool
+        bp, li = xs
         bp = _dequant_tree(bp, _dtype(cfg))
-        cache_out = {}
+        pool = dict(pool)
         for i, spec in enumerate(cfg.block_pattern):
             lp = bp[f"layer_{i}"]
-            ci = cache_in[f"layer_{i}"]
-            co = {}
             if spec.mixer == "attn":
+                pk, pv = pool[f"layer_{i}"]["k"], pool[f"layer_{i}"]["v"]
                 h = L.rms_norm(xc, lp["attn"]["norm"], cfg.norm_eps)
                 q, k, v = _attn_qkv(lp["attn"], cfg, h)  # (B, T, H, Dh)
                 if cfg.use_rope:
@@ -883,10 +893,11 @@ def verify_step_paged(
                     )
                     q = L.apply_rope(q, sin, cos)
                     k = L.apply_rope(k, sin, cos)
-                co["k"] = ci["k"].at[pid, off].set(k.astype(ci["k"].dtype))
-                co["v"] = ci["v"].at[pid, off].set(v.astype(ci["v"].dtype))
-                kg = _gather_pages(co["k"], block_tables).astype(q.dtype)
-                vg = _gather_pages(co["v"], block_tables).astype(q.dtype)
+                pk = pk.at[li, pid, off].set(k.astype(pk.dtype))
+                pv = pv.at[li, pid, off].set(v.astype(pv.dtype))
+                pool[f"layer_{i}"] = {"k": pk, "v": pv}
+                kg = _gather_pages(pk, block_tables, li).astype(q.dtype)
+                vg = _gather_pages(pv, block_tables, li).astype(q.dtype)
                 o = L.verify_attention(
                     q, kg, vg, slot_pos, positions,
                     window=spec.window, softcap=cfg.attn_softcap,
@@ -898,11 +909,12 @@ def verify_step_paged(
             if spec.ffn != "none":
                 out, _ = _ffn(lp, cfg, xc)
                 xc = xc + out
-            cache_out[f"layer_{i}"] = co
-        return xc, cache_out
+        return (xc, pool), None
 
-    x, new_cache = lax.scan(
-        body, x, (params["blocks"], cache), unroll=L.in_analysis_mode()
+    (x, new_cache), _ = lax.scan(
+        body, (x, cache),
+        (params["blocks"], jnp.arange(cfg.n_blocks, dtype=jnp.int32)),
+        unroll=L.in_analysis_mode(),
     )
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_logits(params, cfg, x)  # (B, T, V)

@@ -167,6 +167,123 @@ def test_verify_step_paged_matches_sequential_decode():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _paged_step_layer_by_layer(params, cfg, tokens, pool, lengths, bt):
+    """The paged verify step as a plain Python loop over layers, with the
+    pool as numpy pages: each layer writes its new K/V at ``(page,
+    offset)`` of its own pool, then attends over its table's pages.
+    Returns ``(logits (B, T, V), pool)``."""
+    pool = {name: {kv: np.array(a) for kv, a in e.items()}
+            for name, e in pool.items()}
+    B, T = tokens.shape
+    Pmax = bt.shape[1]
+    scratch, ps = next(iter(pool.values()))["k"].shape[1:3]
+    scratch -= 1
+    positions = np.asarray(lengths)[:, None] + np.arange(T)[None]
+    slot_pos = np.broadcast_to(np.arange(Pmax * ps), (B, Pmax * ps))
+    x = params["embed"][tokens]
+    for li in range(cfg.n_blocks):
+        bp = M._dequant_tree(
+            jax.tree.map(lambda a: a[li], params["blocks"]), M._dtype(cfg)
+        )
+        for i, spec in enumerate(cfg.block_pattern):
+            lp, pages = bp[f"layer_{i}"], pool[f"layer_{i}"]
+            h = L.rms_norm(x, lp["attn"]["norm"], cfg.norm_eps)
+            q, k, v = M._attn_qkv(lp["attn"], cfg, h)
+            if cfg.use_rope:
+                sin, cos = L.rope_sincos(
+                    jnp.asarray(positions), cfg.head_dim, cfg.rope_theta
+                )
+                q, k = L.apply_rope(q, sin, cos), L.apply_rope(k, sin, cos)
+            for b in range(B):
+                for t in range(T):
+                    p = positions[b, t]
+                    page = bt[b, p // ps] if p // ps < Pmax else -1
+                    page = page if page >= 0 else scratch
+                    pages["k"][li, page, p % ps] = k[b, t]
+                    pages["v"][li, page, p % ps] = v[b, t]
+            idx = np.maximum(bt, 0)
+            kg = pages["k"][li][idx].reshape(B, Pmax * ps, *k.shape[2:])
+            vg = pages["v"][li][idx].reshape(B, Pmax * ps, *v.shape[2:])
+            o = L.verify_attention(
+                q, jnp.asarray(kg), jnp.asarray(vg), jnp.asarray(slot_pos),
+                jnp.asarray(positions), window=spec.window,
+                softcap=cfg.attn_softcap,
+            )
+            x = x + o.reshape(B, T, cfg.q_dim) @ lp["attn"]["wo"]
+            out, _ = M._ffn(lp, cfg, x)
+            x = x + out
+    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return M.lm_logits(params, cfg, x), pool
+
+
+@pytest.mark.parametrize("arch", ["llama-3.1-8b", "gemma2-27b"])
+@pytest.mark.parametrize("T", [1, 3])
+def test_paged_step_writes_each_layer_in_place(arch, T):
+    """decode_step_paged (T=1) and verify_step_paged (T=3) over three
+    super-blocks against a layer-by-layer loop: the logits match, each
+    layer's written ``(page, offset)`` holds that layer's new K/V, and
+    every other page of every layer — the scratch page aside — is
+    bitwise the pool it was given."""
+    base = REGISTRY[arch].reduced()
+    cfg = _fp32(dataclasses.replace(
+        base, n_layers=3 * len(base.block_pattern)
+    ))
+    params = M.init_params(cfg, jax.random.key(0))
+    ps, num_pages = 8, 12
+    pool = M.init_paged_cache(cfg, num_pages, ps)
+    keys = iter(jax.random.split(jax.random.key(5), 64))
+    pool = jax.tree.map(
+        lambda a: jax.random.normal(next(keys), a.shape, a.dtype), pool
+    )
+    bt = np.asarray([
+        [5, 2, 9, -1],  # the last window row runs past the table
+        [-1, -1, -1, -1],  # an empty slot: every write goes to scratch
+        [7, 0, 11, 3],
+    ], np.int32)
+    lengths = np.asarray([22, 0, 13], np.int32)
+    tokens = jax.random.randint(jax.random.key(6), (3, T), 0,
+                                cfg.vocab_size)
+    ref_logits, ref_pool = _paged_step_layer_by_layer(
+        params, cfg, tokens, pool, lengths, bt
+    )
+    if T == 1:
+        logits, got = M.decode_step_paged(
+            params, cfg, tokens[:, 0], pool, jnp.asarray(lengths),
+            jnp.asarray(bt),
+        )
+        logits = logits[:, None]
+    else:
+        logits, got = M.verify_step_paged(
+            params, cfg, tokens, pool, jnp.asarray(lengths),
+            jnp.asarray(bt),
+        )
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                               rtol=1e-5, atol=1e-5)
+    written = np.zeros((num_pages + 1, ps), bool)
+    for b in range(3):
+        for p in lengths[b] + np.arange(T):
+            page = bt[b, p // ps] if p // ps < bt.shape[1] else -1
+            if page >= 0:
+                written[page, p % ps] = True
+    for name in pool:
+        for kv in ("k", "v"):
+            before = np.asarray(pool[name][kv])
+            after = np.asarray(got[name][kv])
+            want = ref_pool[name][kv]
+            for li in range(cfg.n_blocks):
+                np.testing.assert_allclose(
+                    after[li][written], want[li][written],
+                    rtol=1e-5, atol=1e-5,
+                    err_msg=f"{name}.{kv} layer {li}: new K/V",
+                )
+                np.testing.assert_array_equal(
+                    after[li, :-1][~written[:-1]],
+                    before[li, :-1][~written[:-1]],
+                    err_msg=f"{name}.{kv} layer {li}: a page it must "
+                            "not touch changed",
+                )
+
+
 def test_accept_prefix_sampling():
     draft = jnp.asarray([[5, 6, 7], [5, 6, 7], [1, 2, 3], [9, 9, 9]])
     target = jnp.asarray([

@@ -13,6 +13,8 @@ workers all import every test file.  These tests stay in this one file
 so that one worker loads the library for all of them.
 """
 import dataclasses
+import math
+import re
 from functools import partial
 
 import jax
@@ -78,6 +80,20 @@ def _device_bytes(compiled) -> int:
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
 
+def _assert_pool_updated_in_place(compiled, cache):
+    """No ``copy`` shaped like a whole pool leaf, and temporaries smaller
+    than one leaf: the step writes the donated pool where it lies,
+    instead of copying it into its layer scan and slicing each layer out
+    and back."""
+    leaf = jax.tree.leaves(cache)[0]
+    shard = leaf.sharding.shard_shape(leaf.shape)
+    dims = ",".join(map(str, shard))
+    copies = re.findall(rf"\w+\[{dims}\]\S* copy\(", compiled.as_text())
+    assert not copies, f"whole-pool copies: {copies}"
+    leaf_bytes = math.prod(shard) * leaf.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
+
+
 def _paged_args(cfg, sharding, batch):
     params = _on(M.param_specs(cfg), sharding)
     cache = _on(
@@ -101,6 +117,24 @@ def test_decode_step_paged_compiles_at_published_widths(cfg, one_chip):
     # the pool is donated: the new pool aliases the old one
     assert m.alias_size_in_bytes > 0
     assert _device_bytes(compiled) < HBM_BYTES
+    _assert_pool_updated_in_place(compiled, cache)
+
+
+def test_verify_step_paged_updates_pool_in_place(cfg, one_chip):
+    """The speculative verify step (T = 4) shares the decode step's layer
+    scan, and so its in-place pool update."""
+    T = 4
+    params, cache, i32 = _paged_args(cfg, one_chip, SLOTS)
+    compiled = jax.jit(
+        partial(M.verify_step_paged_greedy, cfg=cfg),
+        donate_argnames=("cache",),
+    ).lower(
+        params, tokens=i32((SLOTS, T)), cache=cache, lengths=i32((SLOTS,)),
+        block_tables=i32((SLOTS, PMAX)),
+    ).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    assert _device_bytes(compiled) < HBM_BYTES
+    _assert_pool_updated_in_place(compiled, cache)
 
 
 @pytest.mark.parametrize("S", [128, 1024])
@@ -145,6 +179,7 @@ def test_decode_step_paged_compiles_on_a_tp2_slice(cfg, topo):
     assert "all-reduce" in compiled.as_text()
     # per chip: half the pool and (about) half the weights
     assert _device_bytes(compiled) < HBM_BYTES / 2
+    _assert_pool_updated_in_place(compiled, cache)
 
 
 def test_paged_decode_kernel_compiles(one_chip):

@@ -1,11 +1,13 @@
 """One run of one cell: set up, open the window, measure, check.
 
 Everything a cell is made of is found by name: its entry in
-``BENCHMARK.json``, its configuration ``configs/<config>.json``, its
-traffic mix ``traffic/<traffic>.json`` and a reader
-``metrics/<metric>.py`` for each metric that ``BENCHMARK.json`` gives
-the cell.  A new cell, configuration, mix or metric is new files and
-new entries; nothing here names one.
+``BENCHMARK.json``, its configuration ``configs/<config>.json`` and the
+module of its architecture ``archs/<architecture>.py``, its traffic mix
+``traffic/<traffic>.json`` and the generator of its arrivals
+``arrivals/<arrivals>.py``, and a reader ``metrics/<metric>.py`` for
+each metric that ``BENCHMARK.json`` gives the cell.  A new cell,
+configuration, architecture, mix, kind of arrivals or metric is new
+files and new entries; nothing here names one.
 
 The system under test is the program's served path, built as its
 bring-up run builds it: ``PDCluster`` (policy ``voltana``, one prefill
@@ -18,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import shutil
@@ -31,10 +32,12 @@ from typing import Callable, Optional
 import numpy as np
 
 import model
+import progspans
 import reference
 import stats
 import traffic
 import tracereduce
+from byname import load_module
 from wallclock import WallClock
 
 HERE = Path(__file__).resolve().parent
@@ -73,13 +76,7 @@ def metrics_of(bench: dict, cell: str, kind: str) -> list:
 
 
 def reader(name: str, root: Path = HERE):
-    path = root / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise RunError(f"no reader for metric {name!r} ({path})")
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_module(root / "metrics", name, "metric")
 
 
 def peaks_of(kind: str, root: Path = HERE) -> dict:
@@ -113,8 +110,10 @@ class Run:
 def build_cluster(c: dict, mcfg, weights, mix: dict, wall: WallClock,
                   seed: int):
     """PDCluster 1P+1D over paged RealBackends, on the wall clock.  The
-    prefill instance's pool holds the largest prompt (it keeps no pages
-    without a radix cache); the decode instance's pool is the file's."""
+    prefill instance's pool holds the largest prompt and, where the mix
+    turns the prefix cache on, the cache's pages: the cache holds what
+    the pool has beyond one largest prompt.  The decode instance's pool
+    is the file's."""
     from repro.core.power import TPU_V5E
     from repro.serving import ClusterConfig, PDCluster
     from repro.serving.cluster import build_predictor
@@ -131,6 +130,8 @@ def build_cluster(c: dict, mcfg, weights, mix: dict, wall: WallClock,
     def factory(kind, idx, hw, seed, tp=None):
         return real[kind](kind, idx, hw, seed, tp=tp)
 
+    prefix = bool(mix.get("prefix_cache", False))
+    cache_tokens = (pages["prefill"] - int(s["max_len"]) // ps) * ps
     predictor = build_predictor(
         mcfg, TPU_V5E, TPU_V5E.freq_levels_2,
         kv_cap=pages["decode"] * ps, max_running=slots, seed=0)
@@ -138,7 +139,8 @@ def build_cluster(c: dict, mcfg, weights, mix: dict, wall: WallClock,
         model=mcfg, chip=TPU_V5E, n_prefill=1, n_decode=1,
         policy="voltana", paged=True, kv_page_size=ps,
         decode_max_running=slots, kv_capacity_tokens=pages["decode"] * ps,
-        prefix_cache=bool(mix.get("prefix_cache", False)),
+        prefix_cache=prefix,
+        prefix_cache_capacity=cache_tokens if prefix else None,
         predictor=predictor, online_adapt=False, seed=seed,
         # one chip holds both instances: the handoff costs what insert
         # really takes on the host clock, so no modelled transfer delay
@@ -160,23 +162,30 @@ def requests_of(items, vocab: int):
 
 
 def warm_up(cluster, mix: dict, c: dict, seed: int) -> int:
-    """Serve one short request per page count the mix can hand off:
+    """Serve the warm-up prompts of the mix's arrivals, two tokens each:
     every prefill bucket, every handoff gather and insert shape, the
     decode step and the release, off the clock.  Two at a time: the
     prefill instance does not wait for decode slots, so requests sent
     together leave their handed-off pages waiting on the device."""
-    lens = traffic.warmup_lengths(mix, int(c["serving"]["page_size"]))
-    rng = np.random.default_rng(np.random.SeedSequence([seed, 3]))
     V = int(c["vocab_size"])
-    items = [traffic.Item(WARM_RID + i, 0.0,
-                          rng.integers(0, V, n, dtype=np.int32), 2)
-             for i, n in enumerate(lens)]
+    prompts = traffic.warmup_prompts(mix, int(c["serving"]["page_size"]),
+                                     seed, V)
+    items = [traffic.Item(WARM_RID + i, 0.0, p, 2)
+             for i, p in enumerate(prompts)]
     reqs = requests_of(items, V)
     for i in range(0, len(reqs), 2):
         cluster.run(reqs[i: i + 2])
     bad = [r.rid for r in reqs if not r.finished]
     if bad:
         raise RunError(f"warm-up requests unfinished: {bad[:5]}")
+    # the window starts with empty prefix caches: the warm-up's prompts
+    # never come again, and their recency is on the warm-up's clock,
+    # which ran ahead of the window's, so they would outlive its own
+    # (left to eviction, the tests' sessions cell reuses nothing); the
+    # program has no public call that empties the cache
+    for e in cluster.prefill:
+        if e.cache is not None:
+            e.backend._evict_radix_for(e.backend.pool_pages)
     return len(reqs)
 
 
@@ -246,7 +255,7 @@ class TraceWindow:
         if self.state == "on":
             self.wall.pause(self._stop)
 
-    def read(self, c: dict, peaks: dict) -> Optional[dict]:
+    def read(self, c: dict, peaks: dict, prof) -> Optional[dict]:
         if self.state != "done":
             return None
         ev = tracereduce.extract(self.dir)
@@ -256,7 +265,10 @@ class TraceWindow:
             names[m[0]] = names.get(m[0], 0) + 1
         log("trace-modules", **dict(sorted(names.items(),
                                            key=lambda x: -x[1])[:12]))
-        out = tracereduce.reduce(ev, self.wall.dispatches, c, peaks)
+        prefills = progspans.prefill_steps(getattr(prof, "spans", None),
+                                           self.t_on)
+        out = tracereduce.reduce(ev, self.wall.dispatches, c, peaks,
+                                 prefills)
         out["window_s"] = out["span_s"]
         out["device_plane"] = ev["device"]
         return out
@@ -322,8 +334,8 @@ def control_tokens(mcfg, weights, sample):
 
     qblocks = {"layer_0": {
         grp: {k: quant(k, v) if v.ndim >= 3 else v
-              for k, v in weights["blocks"]["layer_0"][grp].items()}
-        for grp in ("attn", "mlp")}}
+              for k, v in leaves.items()}
+        for grp, leaves in weights["blocks"]["layer_0"].items()}}
     qparams = dict(weights, blocks=qblocks)
     out = []
     for r in sample:
@@ -449,6 +461,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     c = config or model.load(cell["config"], HERE)
     mix = mix or traffic.load(cell["traffic"], HERE)
+    # a mix may size the pools its traffic needs
+    c = dict(c, serving=dict(c["serving"], **mix.get("serving", {})))
     e2e = metrics_of(bench, cell_name, "end_to_end")
     layer = metrics_of(bench, cell_name, "per_layer")
     readers = {m["name"]: reader(m["name"])
@@ -524,7 +538,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     result = {"correct": False, "attempted": len(counted), "failed": failed}
     if trace:
-        run.trace = tw.read(c, peaks)
+        run.trace = tw.read(c, peaks, prof)
         tw.close()
         if run.trace is not None:
             t_ = run.trace

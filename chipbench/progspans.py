@@ -86,3 +86,31 @@ def decode_extras(run) -> List[float]:
         return []
     med = statistics.median(plain)
     return [d - med if hit else 0.0 for d, hit in intervals]
+
+
+def prefill_steps(spans, since: float) -> dict:
+    """Request id -> ``(computed, reused)`` tokens of each of its
+    ``step.prefill`` spans that began at or after host time ``since``,
+    in order: what each prefill the trace holds computed and took from
+    the prefix cache."""
+    out: dict = {}
+    for s in spans or ():
+        if s.name == "step.prefill" and s.t0 >= since and s.t1 is not None:
+            out.setdefault(s.rid, []).append(
+                (int(s.attrs["computed"]), int(s.attrs["reused"])))
+    return out
+
+
+def prefill_tokens(run) -> Optional[tuple]:
+    """(reused, computed) prompt tokens over the ``step.prefill`` spans
+    of the requests counted, or None where there are none."""
+    sp = spans_of(run)
+    if sp is None:
+        return None
+    counted = {it.rid for it in run.counted}
+    steps = [s for s in sp if s.name == "step.prefill" and s.t1 is not None
+             and s.rid in counted]
+    if not steps:
+        return None
+    return (sum(s.attrs["reused"] for s in steps),
+            sum(s.attrs["computed"] for s in steps))

@@ -11,7 +11,11 @@ import pytest
 import harness
 import tiny
 
-CELLS = [("phi4-chat", "phi4-mini-3.8b")]
+# the benchmark's cells, and the tests' own prefix-cached sessions cell
+CELLS = [("phi4-chat", "phi4-mini-3.8b"), ("sessions", "phi4-mini-3.8b")]
+# the cells whose mix turns the prefix cache on
+PREFIX_CELLS = [(cell, cfg) for cell, cfg in CELLS
+                if tiny.load_mix(cell).get("prefix_cache")]
 
 
 @pytest.mark.parametrize("cell,cfg", CELLS)
@@ -26,7 +30,7 @@ def test_cell_end_to_end(cell, cfg):
     assert list(last)[-1] == "check"
     assert last["correct"] is True
     assert last["failed"] == 0 and last["attempted"] > 0
-    bench = harness.load_bench(harness.HERE.parent)
+    bench = tiny.bench()
     want = {m["name"] for m in harness.metrics_of(bench, cell, "end_to_end")}
     assert set(last["metrics"]) == want
     assert last["metrics"]["setup_s"]["value"] > 0
@@ -43,6 +47,10 @@ def test_cell_traced(cell, cfg):
     assert not got & {"prefill_mfu", "decode_mfu", "decode_roofline",
                       "window_mfu", "step_gap_ms"}
     assert result["device"]["window_s"] > 0
+    bench = tiny.bench()
+    if "prefix_reuse" in {m["name"] for m in
+                          harness.metrics_of(bench, cell, "per_layer")}:
+        assert result["metrics"]["prefix_reuse"]["value"] > 0
 
 
 def _decode_backend(backends):
@@ -95,11 +103,40 @@ def token_altered(backends):
     b._decode_jit = broken
 
 
+def prefix_pages_wrong(backends):
+    b = [b for b in backends if b._radix is not None][0]
+    match = b._radix.match_pages
+
+    def broken(tokens):
+        n, pages = match(tokens)
+        # one page off: the first cached page left out, the last one
+        # twice (the order of whole pages alone does not change
+        # attention, which reads every page of the span)
+        return n, pages[1:] + pages[-1:]
+
+    b._radix.match_pages = broken
+
+
 @pytest.mark.parametrize("fault", [state_unchanged, half_batch,
                                    token_altered])
 @pytest.mark.parametrize("cell,cfg", CELLS)
 def test_fault_is_not_correct(cell, cfg, fault):
     result = tiny.run(cell, cfg, patch_backends=fault)
+    assert result["correct"] is False, result["check"]
+
+
+@pytest.mark.parametrize("cell,cfg", PREFIX_CELLS)
+def test_prefix_fault_is_not_correct(cell, cfg):
+    """A prefix hit that reads the wrong pages serves wrong tokens, and
+    the check says so; the sound run of the same cell hits the cache."""
+    hits = []
+
+    def fault(backends):
+        prefix_pages_wrong(backends)
+        hits.extend(b for b in backends if b._radix is not None)
+
+    result = tiny.run(cell, cfg, patch_backends=fault)
+    assert hits[0].reused_tokens > 0
     assert result["correct"] is False, result["check"]
 
 

@@ -156,11 +156,16 @@ def idle_by_host(merged, segs, lo: float, hi: float) -> Dict[str, float]:
 
 
 def reduce(ev: dict, dispatches: List[tuple], c: Optional[dict],
-           peaks: Optional[dict]) -> dict:
+           peaks: Optional[dict], prefills: Optional[dict] = None) -> dict:
     """Busy and idle time, the breakdown, and the work of each step.
 
-    ``dispatches``: the adapter's log, ``("prefill", tokens)`` or
+    ``dispatches``: the adapter's log, ``("prefill", tokens, rid)`` or
     ``("decode", contexts, rids)`` per step program, in dispatch order.
+    ``prefills``: request id -> ``(computed, reused)`` tokens of each of
+    its prefills in the trace, in order (the program's ``step.prefill``
+    spans); a prefill is charged its computed tokens after the reused
+    ones, and where one is missing or does not add up to the tokens
+    dispatched, no prefill work is read (``prefill_flops`` None).
     ``c``/``peaks``: the configuration and the device's peaks, for the
     operations and least time of each step (None: times only)."""
     import work
@@ -190,12 +195,20 @@ def reduce(ev: dict, dispatches: List[tuple], c: Optional[dict],
     if c is None or len(steps) != len(dispatches) or not steps:
         return out
     out["steps"] = True
+    todo = {rid: list(v) for rid, v in (prefills or {}).items()}
     prev = None
     for (name, s, d), disp in zip(steps, dispatches):
         if disp[0] == "prefill":
             out["n_prefill"] += 1
             out["prefill_s"] += d
-            out["prefill_flops"] += work.prefill_flops(c, disp[1])
+            done = todo.get(disp[2])
+            if out["prefill_flops"] is not None:
+                if not done or sum(done[0]) != disp[1]:
+                    out["prefill_flops"] = None
+                else:
+                    computed, reused = done.pop(0)
+                    out["prefill_flops"] += work.prefill_flops(
+                        c, computed, reused)
             continue
         ctx, rids = disp[1], set(disp[2])
         f = work.decode_flops(c, ctx)

@@ -20,8 +20,8 @@ Host seconds and calls are counted per backend method, and around each
 call a span of the same name can be written into the profiler's trace
 (``annotate``), so that device idle time can be put down to what the
 host was doing.  ``dispatches`` logs, in order, every step program the
-backends launch (``("prefill", tokens)`` and ``("decode", contexts,
-rids)``), for the trace reduction.
+backends launch (``("prefill", tokens, rid)`` and ``("decode",
+contexts, rids)``), for the trace reduction.
 """
 from __future__ import annotations
 
@@ -173,7 +173,7 @@ class WallClock:
         def call(r):
             if self.log_dispatches:
                 n = r.prompt_len + (r.tokens_out if r.resuming else 0)
-                self.dispatches.append(("prefill", n))
+                self.dispatches.append(("prefill", n, r.rid))
             return fn(r)
 
         return call
